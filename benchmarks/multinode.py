@@ -125,16 +125,15 @@ def allgather_microbench(ndev: int) -> list[tuple[int, int, float]]:
     host->device transfer is not. Returns [(D, nbytes, seconds)]."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     reps = 3 if SMOKE else 7
     pts = []
     for d in [d for d in AG_DEVICES if d <= ndev]:
         mesh = Mesh(np.asarray(jax.devices()[:d]), ("gx",))
-        fn = jax.jit(shard_map(lambda v: jax.lax.all_gather(v, "gx"),
+        fn = jax.jit(jax.shard_map(lambda v: jax.lax.all_gather(v, "gx"),
                                mesh=mesh, in_specs=P("gx"), out_specs=P(),
-                               check_rep=False))
+                               check_vma=False))
         for nb in AG_PAYLOADS:
             x = jax.device_put(jnp.zeros((d * (nb // 4),), jnp.float32),
                                NamedSharding(mesh, P("gx")))

@@ -92,6 +92,8 @@ def main() -> None:
             + " --xla_force_host_platform_device_count=8").strip()
     if args.out_dir:
         os.makedirs(args.out_dir, exist_ok=True)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import importlib
     failures = []

@@ -89,7 +89,7 @@ class FakeShardEngine:
     def compile_count(self):
         return 0
 
-    def search_probed(self, q, probes, *, pad_to=None):
+    def search_probed(self, q, probes, *, pad_to=None, trim=True):
         q = np.asarray(q)
         t_done = max(time.perf_counter(), self.t_free) + self.service_s
         self.t_free = t_done

@@ -241,6 +241,21 @@ def _make_shard_search(cfg: SearchConfig, dim: int):
     return shard_search
 
 
+def _pad_rows(a: np.ndarray, rows: int, fill) -> np.ndarray:
+    """Host-side pad of a batch to ``rows`` rows of ``fill``."""
+    if len(a) == rows:
+        return a
+    pad = np.full((rows - len(a), *a.shape[1:]), fill, a.dtype)
+    return np.concatenate([a, pad])
+
+
+def _trim(out: rerank_mod.RerankResult, nq: int, trim: bool
+          ) -> rerank_mod.RerankResult:
+    if not trim or out.ids.shape[0] == nq:
+        return out
+    return rerank_mod.RerankResult(out.ids[:nq], out.dists[:nq])
+
+
 # ---------------------------------------------------------------------------
 # Engine
 # ---------------------------------------------------------------------------
@@ -371,7 +386,8 @@ class PIMCQGEngine:
 
         return probed_step
 
-    def search_probed(self, queries, probe, *, pad_to: int | None = None
+    def search_probed(self, queries, probe, *, pad_to: int | None = None,
+                      trim: bool = True
                       ) -> tuple[rerank_mod.RerankResult, SearchStats]:
         """Partial search over an EXPLICIT probe set (sharded fleet tier).
 
@@ -380,8 +396,9 @@ class PIMCQGEngine:
         nothing. Returns the exact-reranked top-k over exactly those
         clusters; a row of all -1 probes yields ids -1 / dists inf. With
         pad_to=B the (cached) B-shaped executable is reused and results for
-        real rows are identical to an unpadded call, like ``search``."""
-        queries = jnp.asarray(queries, jnp.float32)
+        real rows are identical to an unpadded call, like ``search``
+        (``trim`` as there)."""
+        queries = np.asarray(queries, np.float32)
         probe = np.asarray(probe, np.int32)
         nq = queries.shape[0]
         if probe.shape[0] != nq:
@@ -395,47 +412,43 @@ class PIMCQGEngine:
                 f"search_probed takes LOCAL cluster ids (did you pass "
                 f"global ids from cluster_filter on an unpartitioned "
                 f"centroid set?)")
-        probe = jnp.asarray(probe)
         p = probe.shape[1]
         b = nq if pad_to is None else int(pad_to)
         if b < nq:
             raise ValueError(f"pad_to={b} < batch size {nq}")
-        if b > nq:
-            queries = jnp.concatenate(
-                [queries, jnp.zeros((b - nq, queries.shape[1]), jnp.float32)])
-            probe = jnp.concatenate(
-                [probe, jnp.full((b - nq, p), -1, jnp.int32)])
+        queries = _pad_rows(queries, b, 0.0)
+        probe = _pad_rows(probe, b, -1)
         key = ("probed", b, p)
         if key not in self._search_cache:
             self._search_cache[key] = self._build_probed_fn(b, p)
         fn = self._search_cache[key]
         out, stats = fn(self.placed, self.index.rotation, self.host.vectors,
                         queries, probe, jnp.int32(nq))
-        if b > nq:
-            out = rerank_mod.RerankResult(out.ids[:nq], out.dists[:nq])
-        return out, stats
+        return _trim(out, nq, trim), stats
 
-    def search(self, queries, *, pad_to: int | None = None
+    def search(self, queries, *, pad_to: int | None = None,
+               trim: bool = True
                ) -> tuple[rerank_mod.RerankResult, SearchStats]:
         """Search; with pad_to=B >= len(queries) the batch is zero-padded to
         bucket B and the (cached) B-shaped executable is reused — results
-        for the real queries are identical to an unpadded search."""
-        queries = jnp.asarray(queries, jnp.float32)
+        for the real queries are identical to an unpadded search.
+
+        Padding happens on the host. ``trim=False`` returns all B rows (the
+        rows past len(queries) are pads): a caller that reads the result on
+        the host trims it there, where a device slice would compile once
+        per distinct batch size while serving."""
+        queries = np.asarray(queries, np.float32)
         nq = queries.shape[0]
         b = nq if pad_to is None else int(pad_to)
         if b < nq:
             raise ValueError(f"pad_to={b} < batch size {nq}")
-        if b > nq:
-            queries = jnp.concatenate(
-                [queries, jnp.zeros((b - nq, queries.shape[1]), jnp.float32)])
+        queries = _pad_rows(queries, b, 0.0)
         if b not in self._search_cache:
             self._search_cache[b] = self._build_search_fn(b)
         fn = self._search_cache[b]
         out, stats = fn(self.placed, self.index.centroids, self.index.rotation,
                         self.host.vectors, queries, jnp.int32(nq))
-        if b > nq:
-            out = rerank_mod.RerankResult(out.ids[:nq], out.dists[:nq])
-        return out, stats
+        return _trim(out, nq, trim), stats
 
     def search_bucketed(self, queries
                         ) -> tuple[rerank_mod.RerankResult, SearchStats]:

@@ -50,7 +50,8 @@ __all__ = ["ExecutionBackend", "InProcBackend", "MeshBackend", "INPROC",
 class ExecutionBackend(Protocol):
     """Where/how a worker's flush actually executes. ``search`` and
     ``search_probed`` mirror the engine entry points (lazy results with
-    async-dispatch semantics: ``.ids.is_ready()`` where supported);
+    async-dispatch semantics: ``.ids.is_ready()`` where supported) and may
+    return the whole padded bucket: the worker keeps the leading rows;
     ``name`` is the registry key reported in TopologyReport."""
 
     name: str
@@ -68,10 +69,11 @@ class InProcBackend:
     name = "inproc"
 
     def search(self, engine, queries, *, pad_to):
-        return engine.search(queries, pad_to=pad_to)
+        return engine.search(queries, pad_to=pad_to, trim=False)
 
     def search_probed(self, engine, queries, probe, *, pad_to):
-        return engine.search_probed(queries, probe, pad_to=pad_to)
+        return engine.search_probed(queries, probe, pad_to=pad_to,
+                                    trim=False)
 
 
 INPROC = InProcBackend()
@@ -197,7 +199,6 @@ class MeshBackend:
     def _build_fn(self, bucket: int, p: int):
         from . import engine as engine_mod
         from . import rerank as rerank_mod
-        from jax.experimental.shard_map import shard_map
 
         cfg, dim = self._scfg, self._dim
         s = self._inner
@@ -238,14 +239,14 @@ class MeshBackend:
                     jax.lax.all_gather(dists, axis))
 
         sh = P(axis)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             block, mesh=self.mesh,
             in_specs=(jax.tree.map(lambda _: sh, self._placed),
                       sh, sh, P(), P(), P(), sh, P()),
             out_specs=(P(), P()),
-            # all_gather makes the outputs replicated, but 0.4.37 cannot
-            # infer that statically for this block
-            check_rep=False))
+            # all_gather makes the outputs replicated; the per-device
+            # route/search block is not checked for that statically
+            check_vma=False))
 
     # -- dispatch ------------------------------------------------------------
     def search_scattered(self, queries: np.ndarray, tables: np.ndarray,

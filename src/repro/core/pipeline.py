@@ -807,8 +807,9 @@ class EngineWorker:
             return True
 
     def _finish(self, idxs, res, _t_dispatch):
-        ids = np.asarray(res.ids)           # blocks until device done
-        ds = np.asarray(res.dists)
+        n = len(idxs)                       # rows past n are bucket pads
+        ids = np.asarray(res.ids)[:n]       # blocks until device done
+        ds = np.asarray(res.dists)[:n]
         self.sink.finish(idxs, ids, ds)
 
     def harvest(self, block: bool = False) -> bool:

@@ -37,7 +37,10 @@ def rerank(queries: jax.Array, cand_ids: jax.Array, vectors: jax.Array,
     safe = jnp.clip(cand_ids, 0)
     cand = vectors[safe]                                           # (Q, C, D)
     c2 = jnp.sum(cand * cand, axis=-1)                             # (Q, C)
-    dots = jnp.einsum("qd,qcd->qc", queries, cand)
+    # exact distances: on a TPU the default precision would round the f32
+    # operands of this contraction to bfloat16
+    dots = jnp.einsum("qd,qcd->qc", queries, cand,
+                      precision=jax.lax.Precision.HIGHEST)
     d2 = q2 + c2 - 2.0 * dots
 
     # dedup (keep-first) + k-selection, dispatched Pallas-vs-ref through the

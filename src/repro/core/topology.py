@@ -678,8 +678,10 @@ class ShardWorker(EngineWorker):
         if self.hedge is not None \
                 and not self.hedge.complete(res, self.shard):
             return                        # lost the race: drop, don't deposit
+        n = len(idxs)                     # rows past n are bucket pads
         self.sink.finish_partial(idxs, self.slot[idxs],
-                                 np.asarray(res.ids), np.asarray(res.dists))
+                                 np.asarray(res.ids)[:n],
+                                 np.asarray(res.dists)[:n])
 
 
 class ShardedSink(StreamSink):

@@ -18,25 +18,23 @@ import threading
 from typing import Any, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (AbstractMesh, AxisType, Mesh, NamedSharding,
+                          PartitionSpec as P)
 
 _state = threading.local()
 
 
-def current_mesh() -> Mesh | None:
+def current_mesh() -> Mesh | AbstractMesh | None:
     m = getattr(_state, "mesh", None)
     if m is not None:
         return m
-    # fall back to the ambient `with mesh:` context if one is active; on
-    # jax 0.4.x that context lives in pxla's thread resources (there is no
-    # jax.sharding.get_abstract_mesh on the pinned version)
-    try:
-        phys = jax.interpreters.pxla.thread_resources.env.physical_mesh
-    except AttributeError:
+    # fall back to the ambient mesh activated with ``jax.set_mesh`` (its
+    # abstract form: the concrete one cannot be read under jit); inside a
+    # shard_map body its axes are Manual and there is nothing to constrain
+    ambient = jax.sharding.get_abstract_mesh()
+    if ambient.empty or AxisType.Manual in ambient.axis_types:
         return None
-    if phys is not None and not getattr(phys, "empty", True):
-        return phys
-    return None
+    return ambient
 
 
 @contextlib.contextmanager

@@ -17,7 +17,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..models.model import Model
 from ..optim import adamw
@@ -70,9 +69,9 @@ def make_dp_train_step(model: Model, opt_cfg: adamw.AdamWConfig, mesh: Mesh,
 
     batch_spec = P(data_axes)
     rep = P()
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(rep, rep, rep, batch_spec),
         out_specs=(rep, rep, rep, rep),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn, donate_argnums=(0, 1, 2))

@@ -40,12 +40,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, bk: int, causal: bool,
 
     def body(j, carry):
         m, l, acc = carry
-        # leading index must be a traced scalar: current pallas interpret
-        # mode rejects a bare python int inside a pl.load index tuple
-        k = pl.load(k_ref, (jnp.int32(0), pl.ds(j * bk, bk), slice(None))
-                    ).astype(jnp.float32)                 # (BK, dk)
-        v = pl.load(v_ref, (jnp.int32(0), pl.ds(j * bk, bk), slice(None))
-                    ).astype(jnp.float32)                 # (BK, dv)
+        k = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)  # (BK, dk)
+        v = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)  # (BK, dv)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if causal:
@@ -74,7 +70,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, bk: int, causal: bool,
                                              "q_offset", "interpret"))
 def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, bq: int = 256, bk: int = 512,
-                        q_offset: int = 0, interpret: bool = True
+                        q_offset: int = 0, interpret: bool = False
                         ) -> jax.Array:
     """q (BH, Sq, dk), k/v (BH, Sk, dk/dv) — heads pre-folded into BH
     (GQA: repeat kv lanes caller-side). Returns (BH, Sq, dv) in q.dtype."""

@@ -106,12 +106,12 @@ def topk_select(cand_ids: jax.Array, dists: jax.Array, *, k: int
     return _ref.topk_select_ref(cand_ids, dists, k=k)
 
 
-def merge_topk(part_ids: jax.Array, part_dists: jax.Array, *, k: int,
-               run: int | None = None) -> tuple[jax.Array, jax.Array]:
+def merge_topk(part_ids: jax.Array, part_dists: jax.Array, *, k: int
+               ) -> tuple[jax.Array, jax.Array]:
     """Merge O pre-sorted per-shard partial top-k runs (the sharded tier's
     origin gather/merge). Kernel and ref are bitwise-identical; see
     kernels/ref.py merge_topk_ref for the exact semantics."""
     if prefer_kernel(part_ids.shape[-1]):
-        return _topk.merge_topk(part_ids, part_dists, k=k, run=run,
+        return _topk.merge_topk(part_ids, part_dists, k=k,
                                 interpret=_interpret())
     return _ref.merge_topk_ref(part_ids, part_dists, k=k)

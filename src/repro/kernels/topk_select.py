@@ -4,21 +4,18 @@ Two kernels over per-query candidate rows (paper Fig 14: host rerank/merge
 is the dominant pipeline stage, and after the sharded tier it sits on the
 critical path of every query):
 
-  * ``topk_select`` — fused dedup + partial-bitonic top-k for the origin
-    rerank (core/rerank.py). Replaces the pure-XLA stable-argsort dedup +
-    ``lax.top_k`` over C = nprobe*ef candidates: duplicates are flagged
-    with one triangular pairwise compare per grid block (VMEM-resident,
-    never a (Q, C, C) XLA intermediate), each m = pow2(k)-wide run is
-    bitonic-sorted, and the runs are folded through a bitonic merge tree
-    truncated to m per level (partial bitonic: the upper half of every
-    merged pair cannot hold a top-k entry, so deeper levels halve).
+  * ``topk_select`` — fused dedup + bitonic top-k for the origin rerank
+    (core/rerank.py). Replaces the pure-XLA stable-argsort dedup +
+    ``lax.top_k`` over C = nprobe*ef candidates: a column is flagged as a
+    duplicate when an earlier column holds the same id (one lane rotation
+    and compare per offset, VMEM-resident, never a (Q, C, C)
+    intermediate), then the row is bitonic-sorted and the first k columns
+    are kept.
 
   * ``merge_topk`` — the gather/merge stage of the sharded tier
     (core/topology.py ShardedSink / mesh search_scattered): O per-shard
-    partial top-k runs per query, each ALREADY sorted ascending with ids
-    disjoint across runs. Skips dedup and the initial sort entirely and
-    runs only the merge tree — O(L log O) compare-exchanges instead of
-    re-sorting the concatenation.
+    partial top-k runs per query, ids disjoint across runs. No dedup, the
+    same sort.
 
 Every compare-exchange orders by the (dist, original column) lexicographic
 key — a strict total order, so the non-stable bitonic network still has a
@@ -26,11 +23,12 @@ unique fixed output and ties resolve to the lower column exactly like
 ``lax.top_k``. Both kernels are bitwise-identical to kernels/ref.py
 (pinned in tests/test_topk_select.py, incl. pads, duplicates and ties).
 
-TPU notes (per the Pallas guide): all iotas are >= 2-D ``broadcasted_iota``;
-the networks are expressed with reshape / flip / where only (regular
-stride-2^j pairing), so no gather and no captured index constants; block
-height BQ is chosen per call to keep the (BQ, C, C) dedup compare under
-~4 MB of VMEM. CPU validation uses interpret=True.
+TPU layout: every value is a 2-D (BQ, W) tile, W a power of two >= 128
+lanes and BQ a multiple of 8 sublanes. A compare-exchange partner
+``i ^ p`` is one of two lane rotations (``pltpu.roll``), picked per lane
+by bit p of the lane index, so the network needs no gather, reshape or
+reversal (Mosaic lowers none of these on lane axes). CPU tests run the
+same kernels with ``interpret=True``.
 """
 
 from __future__ import annotations
@@ -40,252 +38,132 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["topk_select", "merge_topk"]
+__all__ = ["topk_select", "merge_topk", "bitonic_sort", "bitonic_merge",
+           "lane_width"]
 
-_POS_PAD = jnp.iinfo(jnp.int32).max  # tie-break column for padding slots
+_LANES = 128
+_MAX_BLOCK_ELEMS = 1 << 15   # BQ * W per grid step: ~128 KB per operand
 
 
-def _pow2(n: int) -> int:
-    p = 1
-    while p < n:
-        p *= 2
-    return p
+def lane_width(c: int) -> int:
+    """Power-of-two lane width >= c and >= one 128-lane vreg row."""
+    w = _LANES
+    while w < c:
+        w *= 2
+    return w
 
 
 def _klt(d1, p1, d2, p2):
-    """Strict 'key less-than' on the (dist, column) lexicographic order."""
+    """Strict 'key less-than' on the (key, tie) lexicographic order."""
     return (d1 < d2) | ((d1 == d2) & (p1 < p2))
 
 
-def _split_pairs(x, p: int):
-    """(..., 2p*s) -> a, b = elements (i, i + p) with i & p == 0."""
-    s = x.shape[-1] // (2 * p)
-    x5 = x.reshape(*x.shape[:-1], s, 2, p)
-    return x5[..., 0, :], x5[..., 1, :]
+def _exchange(arrs, col, p: int, up):
+    """One compare-exchange stage at stride p over (R, W) lanes. arrs[0:2]
+    are the (key, tie) sort key, the rest ride along. Lane i pairs with
+    i ^ p; where ``up`` the pair is ordered ascending, else descending."""
+    w = col.shape[-1]
+    low = (col & p) == 0
+    other = [jnp.where(low, pltpu.roll(a, w - p, 1), pltpu.roll(a, p, 1))
+             for a in arrs]
+    other_first = _klt(other[0], other[1], arrs[0], arrs[1])
+    # the low lane of an ascending pair keeps the smaller key, the high
+    # lane the larger; a descending pair the other way round (xor, not a
+    # select: Mosaic cannot select between boolean vectors)
+    take = low ^ up ^ other_first
+    return tuple(jnp.where(take, o, a) for o, a in zip(other, arrs))
 
 
-def _join_pairs(a, b):
-    """Inverse of _split_pairs."""
-    s, p = a.shape[-2], a.shape[-1]
-    return jnp.stack([a, b], axis=-2).reshape(*a.shape[:-2], s * 2 * p)
-
-
-def _bitonic_sort_runs(d, ids, pos, m: int):
-    """Sort every m-wide run of the last axis ascending by (d, pos).
-
-    Textbook bitonic sorter: for size = 2..m, stride = size/2..1, exchange
-    (i, i + stride) toward ascending iff (i // size) % 2 == 0 — directions
-    from 2-D+ iota, pairing from reshape, so nothing needs a gather.
-    """
-    lead = d.shape[:-1]
-    nr = d.shape[-1] // m
-    d, ids, pos = (x.reshape(*lead, nr, m) for x in (d, ids, pos))
+def bitonic_sort(*arrs, descending: bool = False):
+    """Sort every row of (R, W) lanes by (arrs[0], arrs[1]); W a power of
+    two. Further arrays are permuted alongside."""
+    col = jax.lax.broadcasted_iota(jnp.int32, arrs[0].shape, 1)
+    w = col.shape[-1]
     size = 2
-    while size <= m:
+    while size <= w:
+        up = ((col & size) == 0) ^ descending
         p = size // 2
         while p >= 1:
-            s = m // (2 * p)
-            shp = (*lead, nr, s, p)
-            sb = jax.lax.broadcasted_iota(jnp.int32, shp, len(shp) - 2)
-            j = jax.lax.broadcasted_iota(jnp.int32, shp, len(shp) - 1)
-            asc = (((sb * 2 * p + j) // size) % 2) == 0
-            ad, bd = _split_pairs(d, p)
-            ai, bi = _split_pairs(ids, p)
-            ap, bp = _split_pairs(pos, p)
-            swap = jnp.where(asc, _klt(bd, bp, ad, ap), _klt(ad, ap, bd, bp))
-            d = _join_pairs(jnp.where(swap, bd, ad), jnp.where(swap, ad, bd))
-            ids = _join_pairs(jnp.where(swap, bi, ai), jnp.where(swap, ai, bi))
-            pos = _join_pairs(jnp.where(swap, bp, ap), jnp.where(swap, ap, bp))
+            arrs = _exchange(arrs, col, p, up)
             p //= 2
         size *= 2
-    return (x.reshape(*lead, nr * m) for x in (d, ids, pos))
+    return arrs
 
 
-def _bitonic_merge_tree_topk(d, ids, pos, m: int, k: int):
-    """(BQ, W) triples, W = m * 2^t, every m-run ascending by (d, pos)
-    (keys distinct). Per level: reverse the right run of each pair (asc ++
-    desc is bitonic), run the ascending bitonic merge (strides m..1), keep
-    the lower half — k <= m, so the upper half can never reach the top-k.
-    Returns the first k columns once a single run remains."""
-    bq = d.shape[0]
-    while d.shape[1] > m:
-        npair = d.shape[1] // (2 * m)
+def bitonic_merge(*arrs):
+    """Sort rows that are already bitonic (an ascending run followed by a
+    descending one) ascending by (arrs[0], arrs[1])."""
+    col = jax.lax.broadcasted_iota(jnp.int32, arrs[0].shape, 1)
+    p = col.shape[-1] // 2
+    while p >= 1:
+        arrs = _exchange(arrs, col, p, True)
+        p //= 2
+    return arrs
 
-        def fold(x):
-            x4 = x.reshape(bq, npair, 2, m)
-            return jnp.concatenate([x4[:, :, 0, :], x4[:, :, 1, ::-1]],
-                                   axis=-1)
-        d3, i3, p3 = fold(d), fold(ids), fold(pos)
-        p = m
-        while p >= 1:
-            ad, bd = _split_pairs(d3, p)
-            ai, bi = _split_pairs(i3, p)
-            ap, bp = _split_pairs(p3, p)
-            swap = _klt(bd, bp, ad, ap)
-            d3 = _join_pairs(jnp.where(swap, bd, ad), jnp.where(swap, ad, bd))
-            i3 = _join_pairs(jnp.where(swap, bi, ai), jnp.where(swap, ai, bi))
-            p3 = _join_pairs(jnp.where(swap, bp, ap), jnp.where(swap, ap, bp))
-            p //= 2
-        d = d3[:, :, :m].reshape(bq, npair * m)
-        ids = i3[:, :, :m].reshape(bq, npair * m)
-        pos = p3[:, :, :m].reshape(bq, npair * m)
+
+def _select_kernel(ids_ref, d_ref, ids_out, d_out, *, k: int, c: int,
+                   dedup: bool):
+    ids = ids_ref[...]                                     # (BQ, W) i32
+    d = d_ref[...]                                         # (BQ, W) f32
+    col = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
+    if dedup:
+        # col i is a duplicate iff an EARLIER col holds the same id (keep-
+        # first, matching the ref's stable-sort dedup): after s rotations
+        # lane i sees col i - s, which is earlier iff i >= s
+        def body(s, carry):
+            rolled, dup = carry
+            rolled = pltpu.roll(rolled, 1, 1)
+            return rolled, jnp.where((rolled == ids) & (col >= s), 1, dup)
+        _, dup = jax.lax.fori_loop(1, c, body, (ids, jnp.zeros_like(ids)))
+        d = jnp.where((ids < 0) | (dup == 1), jnp.inf, d)
+    # pad lanes (col >= c) hold inf and sort after every real column
+    d, _, ids = bitonic_sort(d, col, ids)
     out_d = d[:, :k]
-    out_ids = jnp.where(jnp.isfinite(out_d), ids[:, :k], -1)
-    return out_ids.astype(jnp.int32), out_d.astype(jnp.float32)
-
-
-def _pad_cols(d, ids, pos, width: int):
-    """Right-pad (BQ, C) triples to (BQ, width) with inf / -1 / POS_PAD."""
-    bq, c = d.shape
-    if width == c:
-        return d, ids, pos
-    pd = jnp.full((bq, width - c), jnp.inf, d.dtype)
-    pi = jnp.full((bq, width - c), -1, ids.dtype)
-    pp = jnp.full((bq, width - c), _POS_PAD, pos.dtype)
-    return (jnp.concatenate([d, pd], axis=1),
-            jnp.concatenate([ids, pi], axis=1),
-            jnp.concatenate([pos, pp], axis=1))
-
-
-# ---------------------------------------------------------------------------
-# Kernel 1: topk_select (fused dedup + partial-bitonic top-k)
-# ---------------------------------------------------------------------------
-
-def _topk_select_kernel(ids_ref, d_ref, ids_out, d_out, *, k: int, bq: int,
-                        c: int):
-    ids = ids_ref[...]                                     # (BQ, C) i32
-    d = d_ref[...]                                         # (BQ, C) f32
-
-    # dedup: col i is a duplicate iff any EARLIER col j holds the same id
-    # (keep-first, matching the ref's stable-sort dedup). One triangular
-    # pairwise compare per block — VMEM-resident, sized by the BQ choice.
-    ci = jax.lax.broadcasted_iota(jnp.int32, (bq, c, c), 1)
-    cj = jax.lax.broadcasted_iota(jnp.int32, (bq, c, c), 2)
-    eq = (ids[:, :, None] == ids[:, None, :]) & (cj < ci)
-    dup = jnp.any(eq, axis=2)                              # (BQ, C)
-    bad = (ids < 0) | dup
-    d = jnp.where(bad, jnp.inf, d)
-
-    pos = jax.lax.broadcasted_iota(jnp.int32, (bq, c), 1)
-    m = _pow2(k)
-    width = m * _pow2(-(-c // m))
-    d, ids, pos = _pad_cols(d, ids, pos, width)
-    d, ids, pos = _bitonic_sort_runs(d, ids, pos, m)
-    out_ids, out_d = _bitonic_merge_tree_topk(d, ids, pos, m, k)
-    ids_out[...] = out_ids
+    ids_out[...] = jnp.where(jnp.isfinite(out_d), ids[:, :k], -1)
     d_out[...] = out_d
+
+
+def _select(ids: jax.Array, d: jax.Array, *, k: int, dedup: bool,
+            interpret: bool) -> tuple[jax.Array, jax.Array]:
+    q, c = ids.shape
+    assert d.shape == (q, c), (d.shape, (q, c))
+    assert k <= c, (k, c)
+    w = lane_width(c)
+    bq = 8 * max(1, min(-(-q // 8), _MAX_BLOCK_ELEMS // (8 * w)))
+    qp = -(-q // bq) * bq
+    ids = jnp.pad(ids.astype(jnp.int32), ((0, qp - q), (0, w - c)),
+                  constant_values=-1)
+    d = jnp.pad(d.astype(jnp.float32), ((0, qp - q), (0, w - c)),
+                constant_values=jnp.inf)
+    kernel = functools.partial(_select_kernel, k=k, c=c, dedup=dedup)
+    out_ids, out_d = pl.pallas_call(
+        kernel,
+        grid=(qp // bq,),
+        in_specs=[pl.BlockSpec((bq, w), lambda i: (i, 0)),
+                  pl.BlockSpec((bq, w), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((bq, k), lambda i: (i, 0)),
+                   pl.BlockSpec((bq, k), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((qp, k), jnp.int32),
+                   jax.ShapeDtypeStruct((qp, k), jnp.float32)],
+        interpret=interpret,
+    )(ids, d)
+    return out_ids[:q], out_d[:q]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def topk_select(cand_ids: jax.Array, dists: jax.Array, *, k: int,
-                interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """Fused dedup + top-k. Semantics: kernels/ref.py topk_select_ref."""
-    q, c = cand_ids.shape
-    assert dists.shape == (q, c), (dists.shape, (q, c))
-    assert k <= c, (k, c)
-    # block height: keep the (BQ, C, C) dedup compare under ~4 MB of VMEM
-    bq = max(1, min(8, (1 << 22) // max(1, c * c)))
-    bq = min(bq, max(1, q))
-    q_pad = (-q) % bq
-    if q_pad:
-        cand_ids = jnp.pad(cand_ids, ((0, q_pad), (0, 0)), constant_values=-1)
-        dists = jnp.pad(dists, ((0, q_pad), (0, 0)), constant_values=jnp.inf)
-    grid = (cand_ids.shape[0] // bq,)
-    kernel = functools.partial(_topk_select_kernel, k=k, bq=bq, c=c)
-    ids, d = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bq, c), lambda i: (i, 0)),
-            pl.BlockSpec((bq, c), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bq, k), lambda i: (i, 0)),
-            pl.BlockSpec((bq, k), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((cand_ids.shape[0], k), jnp.int32),
-            jax.ShapeDtypeStruct((cand_ids.shape[0], k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(cand_ids.astype(jnp.int32), dists.astype(jnp.float32))
-    return ids[:q], d[:q]
+    return _select(cand_ids, dists, k=k, dedup=True, interpret=interpret)
 
 
-# ---------------------------------------------------------------------------
-# Kernel 2: merge_topk (bitonic merge of pre-sorted shard partials)
-# ---------------------------------------------------------------------------
-
-def _merge_topk_kernel(ids_ref, d_ref, ids_out, d_out, *, k: int, bq: int,
-                       run: int, m: int, o: int, width: int):
-    ids = ids_ref[...]                                     # (BQ, W) i32
-    d = d_ref[...]                                         # (BQ, W) f32
-    # padded col -> original col (for the lax.top_k lowest-index tie-break):
-    # runs were widened run -> m and the run count o padded to a power of
-    # two; padding slots sort last among equal (inf) distances.
-    pc = jax.lax.broadcasted_iota(jnp.int32, (bq, width), 1)
-    oi, j = pc // m, pc % m
-    pos = jnp.where((oi < o) & (j < run), oi * run + j, _POS_PAD)
-    out_ids, out_d = _bitonic_merge_tree_topk(d, ids, pos, m, k)
-    ids_out[...] = out_ids
-    d_out[...] = out_d
-
-
-@functools.partial(jax.jit, static_argnames=("k", "run", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def merge_topk(part_ids: jax.Array, part_dists: jax.Array, *, k: int,
-               run: int | None = None, interpret: bool = True
-               ) -> tuple[jax.Array, jax.Array]:
-    """Merge O pre-sorted length-``run`` partial top-k runs per query.
-
-    Semantics: kernels/ref.py merge_topk_ref (run defaults to k, the
-    sharded tier's slot layout). Each run must be sorted ascending; ids
-    need no dedup ACROSS runs because the cluster partition makes them
-    disjoint.
-    """
-    if run is None:
-        run = k
-    q, l0 = part_ids.shape
-    assert part_dists.shape == (q, l0), (part_dists.shape, (q, l0))
-    assert l0 % run == 0, (l0, run)
-    o = l0 // run
-    m = _pow2(max(run, k))
-    o_pad = _pow2(o)
-    # widen each run to m and the run count to a power of two (inf / -1
-    # padding sorts last) so the merge tree sees only pow2 shapes
-    ids3 = part_ids.reshape(q, o, run).astype(jnp.int32)
-    d3 = part_dists.reshape(q, o, run).astype(jnp.float32)
-    ids3 = jnp.pad(ids3, ((0, 0), (0, o_pad - o), (0, m - run)),
-                   constant_values=-1)
-    d3 = jnp.pad(d3, ((0, 0), (0, o_pad - o), (0, m - run)),
-                 constant_values=jnp.inf)
-    width = o_pad * m
-    ids2, d2 = ids3.reshape(q, width), d3.reshape(q, width)
-
-    bq = max(1, min(16, (1 << 20) // max(1, width)))
-    bq = min(bq, max(1, q))
-    q_pad = (-q) % bq
-    if q_pad:
-        ids2 = jnp.pad(ids2, ((0, q_pad), (0, 0)), constant_values=-1)
-        d2 = jnp.pad(d2, ((0, q_pad), (0, 0)), constant_values=jnp.inf)
-    grid = (ids2.shape[0] // bq,)
-    kernel = functools.partial(_merge_topk_kernel, k=k, bq=bq, run=run,
-                               m=m, o=o, width=width)
-    ids, d = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bq, width), lambda i: (i, 0)),
-            pl.BlockSpec((bq, width), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bq, k), lambda i: (i, 0)),
-            pl.BlockSpec((bq, k), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((ids2.shape[0], k), jnp.int32),
-            jax.ShapeDtypeStruct((ids2.shape[0], k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(ids2, d2)
-    return ids[:q], d[:q]
+               interpret: bool = False) -> tuple[jax.Array, jax.Array]:
+    """Merge O per-shard partial top-k runs per query (no dedup: the
+    cluster partition makes ids disjoint across runs). Semantics:
+    kernels/ref.py merge_topk_ref."""
+    return _select(part_ids, part_dists, k=k, dedup=False,
+                   interpret=interpret)

@@ -85,8 +85,6 @@ def sharded_rerank(queries, cand_ids, vectors, mesh, *, n_total: int,
     axes combines — the only cross-shard traffic is the (Q, C) id/distance
     tile (MBs).
     """
-    from jax.experimental.shard_map import shard_map
-
     dp_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
     n_dp = 1
     for a in dp_axes:
@@ -110,10 +108,10 @@ def sharded_rerank(queries, cand_ids, vectors, mesh, *, n_total: int,
         return d2
 
     spec_rep = P()
-    d2 = shard_map(
+    d2 = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_rep, spec_rep, P(tuple(dp_axes), None)),
-        out_specs=spec_rep, check_rep=False)(queries, cand_ids, vectors)
+        out_specs=spec_rep, check_vma=False)(queries, cand_ids, vectors)
     # dedup ids (keep first occurrence) then top-k
     c = cand_ids.shape[-1]
     dup = jnp.any((cand_ids[:, None, :] == cand_ids[:, :, None])
@@ -236,6 +234,7 @@ def main():
     import time
 
     from . import hlo_stats
+    from .compile_cache import enable_compile_cache
     from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh
     from .roofline import RooflineTerms
 
@@ -254,6 +253,7 @@ def main():
                          "variant used by the streaming scheduler")
     ap.add_argument("--out", default="results/dryrun")
     args = ap.parse_args()
+    enable_compile_cache()
     out = pathlib.Path(args.out)
     out.mkdir(parents=True, exist_ok=True)
     for mp in {"single": [False], "multi": [True],

@@ -43,6 +43,7 @@ from ..launch.shapes import CELLS, cell_applicable, input_specs
 from ..models.model import (build_model, make_prefill_step, make_serve_step,
                             make_train_step)
 from ..optim import adamw
+from .compile_cache import enable_compile_cache
 
 DP = ("pod", "data")
 
@@ -282,6 +283,7 @@ def main():
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--only-missing", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     archs = list(all_arch_ids()) if args.arch == "all" else args.arch.split(",")
     shapes = list(CELLS) if args.shape == "all" else args.shape.split(",")

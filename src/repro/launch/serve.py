@@ -93,6 +93,7 @@ from ..core.pipeline import StreamingScheduler, bucket_ladder
 from ..core.topology import ServingTopology
 from ..data.synthetic import clustered_vectors, zipf_query_set
 from ..models.model import build_model
+from .compile_cache import enable_compile_cache
 
 
 class QueryEncoder(Protocol):
@@ -501,6 +502,7 @@ def main():
         ap.error("--churn with --fleet > 1 needs --sharded (the typed "
                  "mutable topology; the replicated facade has no day-2 "
                  "mutation path)")
+    enable_compile_cache()
     run(args.arch, args.requests, args.prompt_len, args.gen, args.rag,
         query_encoder=args.encoder, fleet=args.fleet, sharded=args.sharded,
         replicas=args.replicas, exec=args.exec, tenants=args.tenants,

@@ -28,6 +28,7 @@ from ..distributed import sharding as shard_lib
 from ..launch.mesh import make_production_mesh, make_test_mesh
 from ..models.model import build_model, make_train_step
 from ..optim import adamw
+from .compile_cache import enable_compile_cache
 
 
 def preset_config(arch: str, preset: str):
@@ -131,6 +132,7 @@ def main():
     ap.add_argument("--mesh", default="test",
                     choices=["test", "single", "multi"])
     args = ap.parse_args()
+    enable_compile_cache()
     run(args.arch, args.preset, args.steps, args.batch, args.seq,
         args.ckpt_dir, args.ckpt_every, args.resume, args.mesh)
 

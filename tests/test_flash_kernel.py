@@ -48,8 +48,9 @@ def test_flash_fwd_q_offset_decode_chunk():
     q = jax.random.normal(ks[0], (1, 64, 16))
     k = jax.random.normal(ks[1], (1, 64, 16))
     v = jax.random.normal(ks[2], (1, 64, 16))
-    full = flash_attention_fwd(q, k, v, causal=True, bq=16, bk=16)
+    full = flash_attention_fwd(q, k, v, causal=True, bq=16, bk=16,
+                               interpret=True)
     part = flash_attention_fwd(q[:, 32:], k, v, causal=True, bq=16, bk=16,
-                               q_offset=32)
+                               q_offset=32, interpret=True)
     np.testing.assert_allclose(np.asarray(part), np.asarray(full[:, 32:]),
                                atol=2e-5)

@@ -63,7 +63,7 @@ class FakeEngine:
     def compile_count(self):
         return 0
 
-    def search(self, q, *, pad_to=None):
+    def search(self, q, *, pad_to=None, trim=True):
         q = np.asarray(q)
         now = time.perf_counter()
         t_done = max(now, self.t_free) + self.service_s

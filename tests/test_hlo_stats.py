@@ -66,10 +66,9 @@ def test_collective_accounting_psum():
     if len(devs) < 1:
         return
     mesh = jax.make_mesh((1,), ("d",))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    f = shard_map(lambda x: jax.lax.psum(x, "d"), mesh=mesh,
-                  in_specs=P(), out_specs=P(), check_rep=False)
+    f = jax.shard_map(lambda x: jax.lax.psum(x, "d"), mesh=mesh,
+                      in_specs=P(), out_specs=P(), check_vma=False)
     c = jax.jit(f).lower(jax.ShapeDtypeStruct((256,), jnp.float32)).compile()
     t = weighted_totals(c.as_text())
     # single-device psum moves 0 bytes ((g-1)/g = 0)

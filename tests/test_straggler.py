@@ -163,7 +163,7 @@ class _FakeShardEngine:
     def compile_count(self):
         return 0
 
-    def search_probed(self, q, probes, *, pad_to=None):
+    def search_probed(self, q, probes, *, pad_to=None, trim=True):
         q = np.asarray(q)
         t_done = max(time.perf_counter(), self.t_free) + self.service_s
         self.t_free = t_done
@@ -193,13 +193,9 @@ def _fake_topo(n, *, slow_s=None, hedge=None):
                            centroids=centroids, route="round-robin",
                            buckets=(4,), fill_threshold=4,
                            wait_limit_s=1e-3, fifo_depth=2, hedge=hedge)
-    # pre-compile the origin-merge rerank executable: a mid-run jit trace
-    # would stall the poll loop for ~100ms and contaminate the EWMA
-    from repro.core import rerank
-    out = rerank.rerank(jnp.zeros((4, dim), jnp.float32),
-                        jnp.full((4, topo.fanout * topo.k), -1, jnp.int32),
-                        vectors, k=topo.k)
-    np.asarray(out.ids)
+    # pre-compile the origin-merge executable: a mid-run jit trace would
+    # stall the poll loop for ~100ms and contaminate the EWMA
+    topo._warm_merge()
     return topo, groups
 
 

@@ -60,7 +60,7 @@ def test_merge_topk_kernel_bitwise_matches_ref(rng, q, o, run, k):
     ids = jnp.asarray(ids3.reshape(q, o * run))
     d = jnp.asarray(d3.reshape(q, o * run))
     ri, rd = R.merge_topk_ref(ids, d, k=k)
-    ki, kd = tk.merge_topk(ids, d, k=k, run=run, interpret=True)
+    ki, kd = tk.merge_topk(ids, d, k=k, interpret=True)
     np.testing.assert_array_equal(np.asarray(ki), np.asarray(ri))
     np.testing.assert_array_equal(np.asarray(kd), np.asarray(rd))
 

@@ -247,7 +247,7 @@ class FakeShardEngine:
     def compile_count(self):
         return 0
 
-    def search_probed(self, q, probes, *, pad_to=None):
+    def search_probed(self, q, probes, *, pad_to=None, trim=True):
         q = np.asarray(q)
         now = time.perf_counter()
         t_done = max(now, self.t_free) + self.service_s
