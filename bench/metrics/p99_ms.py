@@ -1,0 +1,9 @@
+"""p99_ms: 99th-percentile latency over every query due in the window,
+timed from its scheduled arrival; a shed or unanswered query counts as
++inf (a miss)."""
+
+from bench.metrics_lib import percentile_ms
+
+
+def read(ctx):
+    return percentile_ms(ctx.latency_s, ctx.answered, 99)
