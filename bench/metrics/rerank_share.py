@@ -1,0 +1,10 @@
+"""rerank_share: device time under the ``rerank`` stage scope of the
+serving executable (candidate gather, exact distances, selection), as a
+percentage of device busy time, inside the traced span
+(``spans.stage_share``; ops carry the scope from ``spans.load_events``)."""
+
+from bench import spans
+
+
+def read(ctx):
+    return spans.stage_share(ctx.events or [], "rerank")

@@ -247,8 +247,9 @@ class MulFreeBackend(RankingBackend):
     def rank_ids(self, shard, cl, ids, lane: MulFreeLanes, dim):
         a: MulFreeArrays = shard.arrays
         safe = jnp.clip(ids, 0)
-        sub_codes = shard.codes[cl, safe]             # (R, W) uint8
-        sub_f = a.f_add[cl, safe]                     # (R,) i32
+        with jax.named_scope("expand"):               # the code gathers
+            sub_codes = shard.codes[cl, safe]         # (R, W) uint8
+            sub_f = a.f_add[cl, safe]                 # (R,) i32
         r = self.ranker(sub_codes, sub_f, lane.lut, lane.sumq,
                         a.shift1[cl], a.shift2[cl], dim)
         return jnp.where(ids >= 0, r, INT_MAX)
@@ -297,9 +298,10 @@ class ExactBackend(RankingBackend):
     def rank_ids(self, shard, cl, ids, lane: ExactLanes, dim):
         a: ExactArrays = shard.arrays
         safe = jnp.clip(ids, 0)
-        sub = rabitq.RabitQCodes(shard.codes[cl, safe],
-                                 a.residual_norm[cl, safe],
-                                 a.cos_theta[cl, safe], dim)
+        with jax.named_scope("expand"):               # the code gathers
+            sub = rabitq.RabitQCodes(shard.codes[cl, safe],
+                                     a.residual_norm[cl, safe],
+                                     a.cos_theta[cl, safe], dim)
         d = rabitq.estimate_sqdist(sub, self._qlut(lane))
         return jnp.where(ids >= 0, d.astype(jnp.float32), F32_MAX)
 
@@ -352,7 +354,9 @@ class HammingBackend(RankingBackend):
 
     def rank_ids(self, shard, cl, ids, lane: HammingLanes, dim):
         safe = jnp.clip(ids, 0)
-        r = self._hamming(shard.codes[cl, safe], lane.qcode, dim)
+        with jax.named_scope("expand"):               # the code gathers
+            sub_codes = shard.codes[cl, safe]
+        r = self._hamming(sub_codes, lane.qcode, dim)
         return jnp.where(ids >= 0, r, INT_MAX)
 
     def rank_cluster(self, shard, cl, lane: HammingLanes, dim):

@@ -64,26 +64,33 @@ def beam_search_lane(shard, cl: jax.Array, lane, *,
         frontier = jnp.where(expanded, pad_rank, beam_rank)
         return (i < cfg.max_iters) & (jnp.min(frontier) < pad_rank)
 
+    # each hop's ops sit under one scope: select, expand, visited, rank
     def body(state):
         i, beam_ids, beam_rank, expanded, visited = state
-        # pick the best unexpanded beam entry
-        frontier = jnp.where(expanded, pad_rank, beam_rank)
-        sel = jnp.argmin(frontier)
-        expanded = expanded.at[sel].set(True)
-        node = beam_ids[sel]
+        with jax.named_scope("select"):
+            # pick the best unexpanded beam entry
+            frontier = jnp.where(expanded, pad_rank, beam_rank)
+            sel = jnp.argmin(frontier)
+            expanded = expanded.at[sel].set(True)
+            node = beam_ids[sel]
 
-        nbrs = shard.neighbors[cl, jnp.clip(node, 0)]           # (R,)
-        fresh = (nbrs >= 0) & ~visited[jnp.clip(nbrs, 0)] & (node >= 0)
-        nbrs = jnp.where(fresh, nbrs, -1)
-        visited = visited.at[jnp.clip(nbrs, 0)].set(
-            visited[jnp.clip(nbrs, 0)] | (nbrs >= 0))
-        nrank = rank_ids(nbrs)                                  # (R,)
+        with jax.named_scope("expand"):
+            nbrs = shard.neighbors[cl, jnp.clip(node, 0)]       # (R,)
+        with jax.named_scope("visited"):
+            fresh = (nbrs >= 0) & ~visited[jnp.clip(nbrs, 0)] & (node >= 0)
+            nbrs = jnp.where(fresh, nbrs, -1)
+            visited = visited.at[jnp.clip(nbrs, 0)].set(
+                visited[jnp.clip(nbrs, 0)] | (nbrs >= 0))
+        with jax.named_scope("rank"):
+            nrank = rank_ids(nbrs)                              # (R,)
 
-        # merge beam + neighbors, keep best EF (ascending rank; EF+R tiny)
-        all_ids = jnp.concatenate([beam_ids, nbrs])
-        all_rank = jnp.concatenate([beam_rank, nrank])
-        all_exp = jnp.concatenate([expanded, jnp.zeros((r_deg,), bool)])
-        take = jnp.argsort(all_rank)[:cfg.ef]
+        with jax.named_scope("select"):
+            # merge beam + neighbors, keep the best EF by ascending rank
+            # (EF+R is tiny)
+            all_ids = jnp.concatenate([beam_ids, nbrs])
+            all_rank = jnp.concatenate([beam_rank, nrank])
+            all_exp = jnp.concatenate([expanded, jnp.zeros((r_deg,), bool)])
+            take = jnp.argsort(all_rank)[:cfg.ef]
         return (i + 1, all_ids[take], all_rank[take], all_exp[take], visited)
 
     state = (jnp.int32(0), beam_ids, beam_rank, expanded, visited)

@@ -222,11 +222,12 @@ def _make_shard_search(cfg: SearchConfig, dim: int):
         else beam_search.beam_search_lane
 
     def shard_search(shard: PlacedIndex, rotation, queries, lane_q, lane_cl):
-        safe_q = jnp.clip(lane_q, 0)
-        safe_c = jnp.clip(lane_cl, 0)
-        lanes = backend.prepare_lanes(
-            queries[safe_q], shard.centroids[safe_c], rotation,
-            shard.arrays, safe_c, dim)
+        with jax.named_scope("prepare_lanes"):
+            safe_q = jnp.clip(lane_q, 0)
+            safe_c = jnp.clip(lane_cl, 0)
+            lanes = backend.prepare_lanes(
+                queries[safe_q], shard.centroids[safe_c], rotation,
+                shard.arrays, safe_c, dim)
 
         def one_lane(cl, lane):
             c = jnp.clip(cl, 0)
@@ -236,9 +237,56 @@ def _make_shard_search(cfg: SearchConfig, dim: int):
             gids = jnp.where((res.ids >= 0) & live, gids, -1)
             return gids, res.rank, jnp.where(live, res.hops, 0)
 
-        return jax.vmap(one_lane)(lane_cl, lanes)
+        with jax.named_scope("beam_search"):
+            return jax.vmap(one_lane)(lane_cl, lanes)
 
     return shard_search
+
+
+def _make_probed_search(cfg: SearchConfig, dim: int, bucket: int, p: int,
+                        n_shards: int):
+    """The search of one bucket-padded batch over given probes (-1 =
+    hole), shared by every serving executable: route lanes -> beam search
+    per shard -> gather candidates per query -> exact rerank. Returns
+    f(placed, shard_of, local_slot, rotation, vectors, queries, probe,
+    n_valid) -> (RerankResult, SearchStats); n_valid <= bucket marks the
+    real queries — pads are masked out of routing, search, and rerank.
+    Each stage runs under its own ``jax.named_scope`` (``core.obs``)."""
+    s = n_shards
+    capacity = _lane_capacity(bucket, p, s, cfg.lane_capacity_factor)
+    # capacity an UNPADDED batch of n real queries would get, tabulated
+    # on host so the traced lookup matches the host formula bit-exactly
+    cap_table = jnp.asarray(
+        [_lane_capacity(n, p, s, cfg.lane_capacity_factor)
+         for n in range(bucket + 1)], jnp.int32)
+    shard_fn = _make_shard_search(cfg, dim)
+
+    def probed_search(placed: PlacedIndex, shard_of, local_slot, rotation,
+                      vectors, queries, probe, n_valid):
+        with jax.named_scope("route_lanes"):
+            valid = jnp.arange(bucket, dtype=jnp.int32) < n_valid
+            cap_valid = cap_table[jnp.clip(n_valid, 0, bucket)]
+            lane_q, lane_cl, inv, dropped = route_lanes(
+                probe, shard_of, local_slot, valid, cap_valid,
+                n_shards=s, capacity=capacity)
+        # the whole PlacedIndex pytree maps over its shard axis at once
+        gids, rank, hops = jax.vmap(
+            shard_fn, in_axes=(0, None, None, 0, 0))(
+            placed, rotation, queries, lane_q, lane_cl)
+        with jax.named_scope("rerank"):
+            # gather candidates back per query via the inverse lane map
+            flat_gids = gids.reshape(s * capacity, cfg.ef)
+            safe = jnp.clip(inv, 0)                          # (Q, P)
+            cand = flat_gids[safe]                           # (Q, P, EF)
+            cand = jnp.where((inv >= 0)[..., None], cand, -1)
+            cand = cand.reshape(bucket, p * cfg.ef)
+            out = rerank_mod.rerank(queries, cand, vectors, k=cfg.k)
+            ids = jnp.where(valid[:, None], out.ids, -1)
+            dists = jnp.where(valid[:, None], out.dists, jnp.inf)
+        stats = SearchStats(hops=hops, dropped_lanes=dropped)
+        return rerank_mod.RerankResult(ids, dists), stats
+
+    return probed_search
 
 
 def _pad_rows(a: np.ndarray, rows: int, fill) -> np.ndarray:
@@ -301,50 +349,26 @@ class PIMCQGEngine:
     def _build_search_fn(self, bucket: int):
         """One XLA executable per *bucket* size; n_valid <= bucket marks the
         real queries — pads are masked out of routing, search, and rerank."""
-        cfg, dim = self.scfg, self.icfg.dim
-        s = self.place.n_shards
-        capacity = _lane_capacity(bucket, cfg.nprobe, s,
-                                  cfg.lane_capacity_factor)
-        # capacity an UNPADDED batch of n real queries would get, tabulated
-        # on host so the traced lookup matches the host formula bit-exactly
-        cap_table = jnp.asarray(
-            [_lane_capacity(n, cfg.nprobe, s, cfg.lane_capacity_factor)
-             for n in range(bucket + 1)], jnp.int32)
-        shard_fn = _make_shard_search(cfg, dim)
+        cfg = self.scfg
+        probed_search = _make_probed_search(cfg, self.icfg.dim, bucket,
+                                            cfg.nprobe, self.place.n_shards)
 
         @jax.jit
         def search_step(placed: PlacedIndex, centroids, rotation, vectors,
                         queries, n_valid):
-            probe, pdist = ivf.cluster_filter(queries, centroids,
-                                              nprobe=cfg.nprobe)
-            if cfg.adaptive_tau > 0:
-                # adaptive early termination: easy queries keep fewer
-                # probes; masked probes are -1 holes route_lanes skips
-                keep = ivf.adaptive_keep_mask(
-                    pdist, tau=cfg.adaptive_tau,
-                    min_probes=cfg.adaptive_min_probes,
-                    ladder=cfg.adaptive_ladder)
-                probe = jnp.where(keep, probe, -1)
-            valid = jnp.arange(bucket, dtype=jnp.int32) < n_valid
-            cap_valid = cap_table[jnp.clip(n_valid, 0, bucket)]
-            lane_q, lane_cl, inv, dropped = route_lanes(
-                probe, self.shard_of, self.local_slot, valid, cap_valid,
-                n_shards=s, capacity=capacity)
-            # the whole PlacedIndex pytree maps over its shard axis at once
-            gids, rank, hops = jax.vmap(
-                shard_fn, in_axes=(0, None, None, 0, 0))(
-                placed, rotation, queries, lane_q, lane_cl)
-            # gather candidates back per query via the inverse lane map
-            flat_gids = gids.reshape(s * capacity, cfg.ef)
-            safe = jnp.clip(inv, 0)                          # (Q, P)
-            cand = flat_gids[safe]                           # (Q, P, EF)
-            cand = jnp.where((inv >= 0)[..., None], cand, -1)
-            cand = cand.reshape(bucket, cfg.nprobe * cfg.ef)
-            out = rerank_mod.rerank(queries, cand, vectors, k=cfg.k)
-            ids = jnp.where(valid[:, None], out.ids, -1)
-            dists = jnp.where(valid[:, None], out.dists, jnp.inf)
-            stats = SearchStats(hops=hops, dropped_lanes=dropped)
-            return rerank_mod.RerankResult(ids, dists), stats
+            with jax.named_scope("cluster_filter"):
+                probe, pdist = ivf.cluster_filter(queries, centroids,
+                                                  nprobe=cfg.nprobe)
+                if cfg.adaptive_tau > 0:
+                    # adaptive early termination: easy queries keep fewer
+                    # probes; masked probes are -1 holes route_lanes skips
+                    keep = ivf.adaptive_keep_mask(
+                        pdist, tau=cfg.adaptive_tau,
+                        min_probes=cfg.adaptive_min_probes,
+                        ladder=cfg.adaptive_ladder)
+                    probe = jnp.where(keep, probe, -1)
+            return probed_search(placed, self.shard_of, self.local_slot,
+                                 rotation, vectors, queries, probe, n_valid)
 
         return search_step
 
@@ -354,35 +378,14 @@ class PIMCQGEngine:
         the partial-search entry point of the sharded fleet tier, where the
         origin host owns probe selection and this engine owns only a
         disjoint cluster slice. One executable per (bucket, P) shape."""
-        cfg, dim = self.scfg, self.icfg.dim
-        s = self.place.n_shards
-        capacity = _lane_capacity(bucket, p, s, cfg.lane_capacity_factor)
-        cap_table = jnp.asarray(
-            [_lane_capacity(n, p, s, cfg.lane_capacity_factor)
-             for n in range(bucket + 1)], jnp.int32)
-        shard_fn = _make_shard_search(cfg, dim)
+        probed_search = _make_probed_search(self.scfg, self.icfg.dim, bucket,
+                                            p, self.place.n_shards)
 
         @jax.jit
         def probed_step(placed: PlacedIndex, rotation, vectors, queries,
                         probe, n_valid):
-            valid = jnp.arange(bucket, dtype=jnp.int32) < n_valid
-            cap_valid = cap_table[jnp.clip(n_valid, 0, bucket)]
-            lane_q, lane_cl, inv, dropped = route_lanes(
-                probe, self.shard_of, self.local_slot, valid, cap_valid,
-                n_shards=s, capacity=capacity)
-            gids, rank, hops = jax.vmap(
-                shard_fn, in_axes=(0, None, None, 0, 0))(
-                placed, rotation, queries, lane_q, lane_cl)
-            flat_gids = gids.reshape(s * capacity, cfg.ef)
-            safe = jnp.clip(inv, 0)                          # (Q, P)
-            cand = flat_gids[safe]                           # (Q, P, EF)
-            cand = jnp.where((inv >= 0)[..., None], cand, -1)
-            cand = cand.reshape(bucket, p * cfg.ef)
-            out = rerank_mod.rerank(queries, cand, vectors, k=cfg.k)
-            ids = jnp.where(valid[:, None], out.ids, -1)
-            dists = jnp.where(valid[:, None], out.dists, jnp.inf)
-            stats = SearchStats(hops=hops, dropped_lanes=dropped)
-            return rerank_mod.RerankResult(ids, dists), stats
+            return probed_search(placed, self.shard_of, self.local_slot,
+                                 rotation, vectors, queries, probe, n_valid)
 
         return probed_step
 

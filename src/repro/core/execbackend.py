@@ -21,10 +21,10 @@ through an ``ExecutionBackend`` instead of calling the engine directly.
     CPU meshes; a multi-process ``jax.distributed`` launch builds the same
     mesh over per-host devices and runs the identical code path.
 
-Bit-parity contract: the per-device block mirrors the in-process
-``engine._build_probed_fn`` computation exactly (same lane capacity
-formula, same cap table, same route/search/gather/rerank sequence), so
-the mesh backend's partial top-k per shard — and hence the origin merge —
+Bit-parity contract: the per-device block runs the in-process probed
+search itself (``engine._make_probed_search``: the same lane capacity
+formula, cap table and route/search/gather/rerank sequence), so the mesh
+backend's partial top-k per shard — and hence the origin merge —
 is bit-identical to the in-process backend and to a single engine
 searching the same probed clusters (pinned in tests/test_execbackend.py
 for shards {2, 4} on a forced 8-device host mesh).
@@ -198,52 +198,32 @@ class MeshBackend:
     # -- compiled step per (bucket, nprobe) shape ---------------------------
     def _build_fn(self, bucket: int, p: int):
         from . import engine as engine_mod
-        from . import rerank as rerank_mod
 
-        cfg, dim = self._scfg, self._dim
-        s = self._inner
         axis = self.axis
-        capacity = engine_mod._lane_capacity(bucket, p, s,
-                                             cfg.lane_capacity_factor)
-        cap_table = jnp.asarray(
-            [engine_mod._lane_capacity(n, p, s, cfg.lane_capacity_factor)
-             for n in range(bucket + 1)], jnp.int32)
-        shard_fn = engine_mod._make_shard_search(cfg, dim)
+        probed_search = engine_mod._make_probed_search(
+            self._scfg, self._dim, bucket, p, self._inner)
 
         def block(placed, shard_of, local_slot, rotation, vectors,
                   queries, probe, n_valid):
             # per-device view: squeeze the owner axis (block size 1), then
-            # run EXACTLY the in-process _build_probed_fn computation so
-            # per-shard partial top-k is bit-identical to exec='inproc'
-            pl = jax.tree.map(lambda a: a[0], placed)
-            pr = probe[0]
-            valid = jnp.arange(bucket, dtype=jnp.int32) < n_valid
-            cap_valid = cap_table[jnp.clip(n_valid, 0, bucket)]
-            lane_q, lane_cl, inv, _dropped = engine_mod.route_lanes(
-                pr, shard_of[0], local_slot[0], valid, cap_valid,
-                n_shards=s, capacity=capacity)
-            gids, rank, hops = jax.vmap(
-                shard_fn, in_axes=(0, None, None, 0, 0))(
-                pl, rotation, queries, lane_q, lane_cl)
-            flat_gids = gids.reshape(s * capacity, cfg.ef)
-            safe = jnp.clip(inv, 0)
-            cand = flat_gids[safe]
-            cand = jnp.where((inv >= 0)[..., None], cand, -1)
-            cand = cand.reshape(bucket, p * cfg.ef)
-            out = rerank_mod.rerank(queries, cand, vectors, k=cfg.k)
-            ids = jnp.where(valid[:, None], out.ids, -1)
-            dists = jnp.where(valid[:, None], out.dists, jnp.inf)
-            # the gather leg: every shard's partials to every device; the
-            # origin (host) reads the replicated (O, B, k) result once
-            return (jax.lax.all_gather(ids, axis),
-                    jax.lax.all_gather(dists, axis))
+            # run the in-process probed search so per-shard partial top-k
+            # is bit-identical to exec='inproc'
+            out, stats = probed_search(
+                jax.tree.map(lambda a: a[0], placed), shard_of[0],
+                local_slot[0], rotation, vectors, queries, probe[0], n_valid)
+            # the gather leg: every shard's partials (and its lane stats)
+            # to every device; the origin (host) reads the replicated
+            # (O, B, k) result once
+            return tuple(jax.lax.all_gather(a, axis) for a in
+                         (out.ids, out.dists, stats.hops,
+                          stats.dropped_lanes))
 
         sh = P(axis)
         return jax.jit(jax.shard_map(
             block, mesh=self.mesh,
             in_specs=(jax.tree.map(lambda _: sh, self._placed),
                       sh, sh, P(), P(), P(), sh, P()),
-            out_specs=(P(), P()),
+            out_specs=(P(), P(), P(), P()),
             # all_gather makes the outputs replicated; the per-device
             # route/search block is not checked for that statically
             check_vma=False))
@@ -252,9 +232,10 @@ class MeshBackend:
     def search_scattered(self, queries: np.ndarray, tables: np.ndarray,
                          *, pad_to: int):
         """One scattered flush: queries (B', D) with their per-owner probe
-        tables (O, B', P) -> lazy (ids (O, B, k), dists (O, B, k)), B =
-        pad_to. Row o is owner o's partial top-k (-1/inf where the owner
-        was not touched), already gathered to the origin."""
+        tables (O, B', P) -> (lazy (ids (O, B, k), dists (O, B, k)),
+        SearchStats (hops (O, S, L), dropped_lanes (O,))), B = pad_to.
+        Row o is owner o's partial top-k (-1/inf where the owner was not
+        touched), already gathered to the origin."""
         if not self._ready:
             raise RuntimeError("MeshBackend.prepare() was never called — "
                                "construct it through ServingTopology")
@@ -270,11 +251,13 @@ class MeshBackend:
             self._cache[key] = self._build_fn(b, p)
         from ..distributed import sharding as sharding_mod
         with sharding_mod.use_mesh(self.mesh):
-            ids, dists = self._cache[key](
+            ids, dists, hops, dropped = self._cache[key](
                 self._placed, self._shard_of, self._local_slot,
                 self._rotation, self._vectors, jnp.asarray(qb),
                 jnp.asarray(tb), jnp.int32(nq))
-        return types.SimpleNamespace(ids=ids, dists=dists)
+        from .engine import SearchStats
+        return (types.SimpleNamespace(ids=ids, dists=dists),
+                SearchStats(hops=hops, dropped_lanes=dropped))
 
     # EngineWorker reads engine.compile_count for its report; the mesh
     # worker's "engine" is this backend, whose executables live in _cache
@@ -290,7 +273,7 @@ class MeshBackend:
             q1 = np.zeros((1, self._dim), np.float32)
             t1 = np.full((self._n_owners, 1, nprobe), -1, np.int32)
             t1[0, 0, 0] = 0
-            res = self.search_scattered(q1, t1[:, :1], pad_to=int(b))
+            res, _ = self.search_scattered(q1, t1[:, :1], pad_to=int(b))
             np.asarray(res.ids)
         return self.compile_count - before
 

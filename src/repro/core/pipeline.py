@@ -40,9 +40,11 @@ import heapq
 import math
 import time
 from collections import deque
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import numpy as np
+
+from . import obs
 
 __all__ = [
     "LinkModel", "UPMEM_LINK", "TPU_ICI_LINK", "PCIE_LINK",
@@ -696,9 +698,10 @@ def resolve_stream_params(engine, buckets, costs: StageCosts | None,
 
 class StreamSink:
     """Per-run shared state of one query stream: the query matrix, arrival
-    times, output arrays, and the run clock. Workers write completed
-    batches here; a fleet shares ONE sink across all its workers so the
-    reassembled output is indistinguishable from a single engine's."""
+    times, output arrays, the run clock, and the run's flush ids, counters
+    and idle span (``core.obs``). Workers write completed batches here; a
+    fleet shares ONE sink across all its workers so the reassembled output
+    is indistinguishable from a single engine's."""
 
     def __init__(self, queries: np.ndarray, arrivals: np.ndarray, k: int):
         self.q = queries
@@ -709,9 +712,17 @@ class StreamSink:
         self.lat = np.full(n, np.nan)
         self.on_finish = None   # optional callback(idxs) at completion —
         self._t0 = time.perf_counter()  # e.g. per-tenant credit release
+        self.n_dispatched = 0   # flush ids, in dispatch order
+        self.counters = obs.Counters()
+        self.idle = obs.Idle()
 
     def now(self) -> float:
         return time.perf_counter() - self._t0
+
+    def span(self, name: str, **args):
+        """A host span of the run's work; it ends the idle stretch."""
+        self.idle.wake()
+        return obs.span(name, **args)
 
     def finish(self, idxs: np.ndarray, ids: np.ndarray, dists: np.ndarray):
         tc = self.now()
@@ -720,6 +731,15 @@ class StreamSink:
         self.lat[idxs] = tc - self.arr[idxs]
         if self.on_finish is not None:
             self.on_finish(idxs)
+
+
+class Flight(NamedTuple):
+    """One dispatched execution in a worker's in-flight FIFO."""
+    idxs: np.ndarray         # query indices into the sink
+    res: object              # lazy result (.ids / .dists)
+    t: float                 # stream time of the dispatch
+    stats: object            # SearchStats of the execution, or None
+    flush: int               # flush id (StreamSink.n_dispatched)
 
 
 class EngineWorker:
@@ -752,7 +772,7 @@ class EngineWorker:
         self.wait_limit_s = wait_limit_s
         self.fifo_depth = fifo_depth
         self.buf: list[int] = []            # admitted, not yet dispatched
-        self.inflight: deque = deque()      # (query_indices, lazy result, t)
+        self.inflight: deque = deque()      # Flight records
         self.flush_sizes: list[int] = []
         self.max_in_flight = 0
         self._compiles0 = engine.compile_count
@@ -812,19 +832,38 @@ class EngineWorker:
         ds = np.asarray(res.dists)[:n]
         self.sink.finish(idxs, ids, ds)
 
+    def _enqueue(self, take, res, t: float, stats):
+        """Put a dispatched execution in the in-flight FIFO under the
+        run's next flush id."""
+        fid = self.sink.n_dispatched
+        self.sink.n_dispatched += 1
+        self.inflight.append(Flight(np.asarray(take), res, t, stats, fid))
+        self.max_in_flight = max(self.max_in_flight, len(self.inflight))
+
+    def _complete(self, f: Flight):
+        """Finish one execution, then count its stats (read after the ids,
+        so counting adds no wait on the device)."""
+        with self.sink.span("serve.finish", flush=f.flush):
+            wait = getattr(f.res.ids, "block_until_ready", None)
+            if wait is not None:
+                with obs.span("serve.block"):
+                    wait()
+            self._finish(f.idxs, f.res, f.t)
+            self.sink.counters.add_flush(f.stats)
+
     def harvest(self, block: bool = False) -> bool:
         got = False
         if block and self.inflight:
-            self._finish(*self.inflight.popleft())
+            self._complete(self.inflight.popleft())
             got = True
         pending = list(self.inflight)
         self.inflight.clear()
-        for rec in pending:                 # out-of-order completion
-            if self._ready(rec[1]):
-                self._finish(*rec)
+        for f in pending:                   # out-of-order completion
+            if self._ready(f.res):
+                self._complete(f)
                 got = True
             else:
-                self.inflight.append(rec)
+                self.inflight.append(f)
         return got
 
     def flush_due(self, t: float, drain: bool) -> bool:
@@ -843,10 +882,13 @@ class EngineWorker:
         if not block_when_full and self.credits <= 0:
             return False                    # backpressure: refuse, don't stall
         take = self.buf[:self.max_bucket]
-        del self.buf[:len(take)]
-        res, _ = self._dispatch(take)                # async device dispatch
-        self.inflight.append((np.asarray(take), res, t))
-        self.max_in_flight = max(self.max_in_flight, len(self.inflight))
+        with self.sink.span("serve.flush", flush=self.sink.n_dispatched,
+                            rows=len(take),
+                            bucket=self._bucket_for(len(take))):
+            del self.buf[:len(take)]
+            with obs.span("serve.dispatch"):
+                res, stats = self._dispatch(take)    # async device dispatch
+            self._enqueue(take, res, t, stats)
         self.flush_sizes.append(len(take))
         if block_when_full and len(self.inflight) >= self.fifo_depth:
             self.harvest(block=True)        # FIFO flow control

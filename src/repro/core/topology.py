@@ -55,7 +55,6 @@ import copy
 import dataclasses
 import functools
 import math
-import time
 import warnings
 from collections import deque
 
@@ -68,6 +67,7 @@ from . import compact_index as compact_index_mod
 from . import engine as engine_mod
 from . import execbackend as execbackend_mod
 from . import ivf as ivf_mod
+from . import obs
 from . import placement as placement_mod
 from ..kernels import ops as kernel_ops
 from .pipeline import (EngineWorker, StageCosts, StreamSink, percentile_ms,
@@ -666,12 +666,15 @@ class ShardWorker(EngineWorker):
         the in-flight FIFO directly (no buffer, no credit check: the
         queries were already admitted and dealt — hedging trades bounded
         duplicate work, capped by max_reissue, for tail latency)."""
-        res, _ = self.exec.search_probed(
-            self.engine, self.sink.q[idxs], self.probes[idxs],
-            pad_to=self._bucket_for(len(idxs)))
-        self.hedge.bind(res, bid)
-        self.inflight.append((np.asarray(idxs), res, t))
-        self.max_in_flight = max(self.max_in_flight, len(self.inflight))
+        bucket = self._bucket_for(len(idxs))
+        with self.sink.span("serve.flush", flush=self.sink.n_dispatched,
+                            rows=len(idxs), bucket=bucket, hedge=bid):
+            with obs.span("serve.dispatch"):
+                res, stats = self.exec.search_probed(
+                    self.engine, self.sink.q[idxs], self.probes[idxs],
+                    pad_to=bucket)
+            self.hedge.bind(res, bid)
+            self._enqueue(idxs, res, t, stats)
         self.n_hedged += 1
 
     def _finish(self, idxs, res, _t_dispatch):
@@ -838,10 +841,9 @@ class MeshShardWorker(EngineWorker):
 
     def _dispatch(self, take):
         t = np.asarray(take)
-        res = self.backend.search_scattered(
+        return self.backend.search_scattered(
             self.sink.q[t], self.tables[:, t, :],
             pad_to=self._bucket_for(len(t)))
-        return res, None
 
     def _finish(self, idxs, res, _t_dispatch):
         nq = len(idxs)
@@ -963,6 +965,10 @@ class TopologyReport:
     # through part_of: it counts the owner the router actually chose, so
     # it is the skew signal RebalancePolicy watches and the denominator
     # for the benchmark's hottest-shard heat share.
+    counters: dict = dataclasses.field(default_factory=dict)
+    # the run's obs.Counters: flushes, lane_slots, live_lanes, hops,
+    # slot_hops, dropped_lanes over every harvested execution, and
+    # gc_collections/gc_s of the collector inside the run
 
 
 class ServingTopology:
@@ -1506,12 +1512,15 @@ class ServingTopology:
         take = np.asarray(take)
         nq = len(take)
         b = next(bb for bb in self.buckets if bb >= nq)
-        cb = np.full((b, sink.part_ids.shape[1]), -1, np.int32)
-        cb[:nq] = sink.part_ids[take]
-        db = np.full((b, sink.part_d.shape[1]), np.inf, np.float32)
-        db[:nq] = sink.part_d[take]
-        out_ids, out_d = self._merge_fn(jnp.asarray(cb), jnp.asarray(db))
-        sink.finish(take, np.asarray(out_ids)[:nq], np.asarray(out_d)[:nq])
+        with sink.span("serve.merge", rows=nq):
+            cb = np.full((b, sink.part_ids.shape[1]), -1, np.int32)
+            cb[:nq] = sink.part_ids[take]
+            db = np.full((b, sink.part_d.shape[1]), np.inf, np.float32)
+            db[:nq] = sink.part_d[take]
+            out_ids, out_d = self._merge_fn(jnp.asarray(cb),
+                                            jnp.asarray(db))
+            sink.finish(take, np.asarray(out_ids)[:nq],
+                        np.asarray(out_d)[:nq])
         merge_sizes.append(nq)
         return True
 
@@ -1563,8 +1572,9 @@ class ServingTopology:
         hedge_rt = None
         served = owner_sel = None
         if self.sharded:
-            tables, touches, served, owner_sel = self._route_probes(
-                q, backend, specs, tenant_of)
+            with obs.span("serve.route"):
+                tables, touches, served, owner_sel = self._route_probes(
+                    q, backend, specs, tenant_of)
             slots = np.cumsum(touches, axis=1) - 1
             pending = touches.sum(axis=1).astype(np.int32)
             sink = ShardedSink(q, arr, self.k, self.fanout)
@@ -1607,14 +1617,17 @@ class ServingTopology:
         merge_sizes: list = []
 
         def shed_one(idx: int, wait: float):
-            shed[idx] = True
-            shed_wait[idx] = wait
+            with sink.span("serve.shed", query=idx):
+                shed[idx] = True
+                shed_wait[idx] = wait
 
         self._active = (root, sink)
         try:
-            self._run_loop(root, sink, adm, arr, order, n, shed_one,
-                           quantum, merge_sizes, ticker)
+            with obs.gc_spans(sink.counters):
+                self._run_loop(root, sink, adm, arr, order, n, shed_one,
+                               quantum, merge_sizes, ticker)
         finally:
+            sink.idle.wake()
             self._active = None
         makespan = sink.now()
         # per-tenant k: truncate the tenant's result rows to its promised
@@ -1678,13 +1691,13 @@ class ServingTopology:
                 nxt = min(nxt, sink.ready[0][1] + self.wait_limit_s)
             if not math.isfinite(nxt):
                 if not root.block_harvest_one():
-                    time.sleep(5e-5)      # transient: nothing due anywhere
+                    sink.idle.sleep(5e-5)  # transient: nothing due anywhere
                 continue
             # dt <= 0 means a deadline already passed but the tree is out
             # of credits — nap briefly instead of spinning until a device
             # frees a slot
             dt = nxt - sink.now()
-            time.sleep(min(max(dt, 5e-5), 5e-4))
+            sink.idle.sleep(min(max(dt, 5e-5), 5e-4))
 
     def _resolve_stream_tenants(self, tenant, n: int):
         """Map run(tenant=...) onto the registry: (specs, tenant_of)."""
@@ -1855,7 +1868,8 @@ class ServingTopology:
             tenants=self._tenant_stats(sink, shed, makespan, specs,
                                        tenant_of, adm, served),
             cluster_hits=cluster_hits,
-            shard_probes=shard_probes)
+            shard_probes=shard_probes,
+            counters=dataclasses.asdict(sink.counters))
 
 
 @dataclasses.dataclass(frozen=True)
