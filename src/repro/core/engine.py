@@ -143,8 +143,15 @@ def placed_specs(n_shards: int, clusters_per_shard: int, budget: int,
 def _lane_capacity(nq: int, nprobe: int, n_shards: int, factor: float) -> int:
     """Per-shard lane-buffer size for an nq-query batch (host-side math;
     also tabulated per n_valid so padded executables drop lanes exactly
-    like the unpadded executable would)."""
-    return max(1, int(np.ceil(nq * nprobe / n_shards * factor)))
+    like the unpadded executable would).
+
+    ``factor`` is headroom over a shard's even share nq*nprobe/n_shards,
+    capped at the nq*nprobe lanes the batch has: a slot past that is never
+    filled, yet runs the beam loop like a live one. nq*nprobe is the only
+    bound that always holds (``search_probed`` probes may repeat a
+    cluster)."""
+    even = int(np.ceil(nq * nprobe / n_shards * factor))
+    return max(1, min(even, nq * nprobe))
 
 
 @functools.partial(jax.jit, static_argnames=("n_shards", "capacity"))

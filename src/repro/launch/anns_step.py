@@ -140,7 +140,8 @@ def build_search_step(s: AnnsScale, n_shards: int, scan: str = "beam",
                            % n_shards)
     local_slot = jnp.asarray(np.arange(s.n_clusters, dtype=np.int32)
                              // n_shards)
-    capacity = int(np.ceil(s.queries * s.nprobe / n_shards * 2.0))
+    capacity = engine._lane_capacity(s.queries, s.nprobe, n_shards,
+                                     scfg.lane_capacity_factor)
     shard_fn = _make_shard_search(scfg, s.dim)
 
     def search_step(placed, centroids, rotation, vectors, queries,

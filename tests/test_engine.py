@@ -88,6 +88,79 @@ def test_route_lanes_inverse_map():
             assert lane_cl[s, l] == int(probe[qi, pi]) // 4
 
 
+@pytest.mark.parametrize("nq,nprobe,shards,factor,want", [
+    (64, 8, 1, 2.0, 512),      # one shard: capped at the batch's lanes
+    (16, 3, 2, 2.0, 48),       # two shards at 2.0: the cap is the share
+    (64, 8, 4, 2.0, 256),      # four shards: the cap does not bind
+    (64, 8, 2, 4.0, 512),      # headroom past the batch is capped
+    (64, 8, 1, 0.05, 26),      # a tight buffer stays tight
+    (0, 8, 1, 2.0, 1),         # an empty batch still gets one slot
+])
+def test_lane_capacity(nq, nprobe, shards, factor, want):
+    assert engine._lane_capacity(nq, nprobe, shards, factor) == want
+
+
+@pytest.mark.parametrize("factor", [1.0, 2.0, 8.0])
+def test_lane_capacity_table_is_the_lanes_at_one_shard(factor):
+    """The cap table of a padded executable gives n real queries the
+    n * nprobe slots they have, so padded and unpadded batches drop the
+    same lanes (none) at any headroom."""
+    table = [engine._lane_capacity(n, 8, 1, factor) for n in range(65)]
+    assert table == [1] + [n * 8 for n in range(1, 65)]
+
+
+@pytest.fixture(scope="module")
+def one_shard(corpus):
+    x, _, _ = corpus
+    icfg = compact_index.IndexConfig(dim=64, n_clusters=16, degree=16,
+                                     knn_k=32)
+    idx, host = compact_index.build_compact_index(
+        jax.random.PRNGKey(0), x, icfg)
+    sizes = np.asarray(idx.n_valid).astype(np.float64)
+    pl = placement.greedy_place(sizes, sizes, 1)
+    return idx, host, pl, icfg
+
+
+def _uncapped_capacity(nq, nprobe, n_shards, factor):
+    """The lane buffer before the cap: headroom past the batch's lanes."""
+    return max(1, int(np.ceil(nq * nprobe / n_shards * factor)))
+
+
+@pytest.mark.parametrize("factor", [1.0, 2.0, 8.0])
+@pytest.mark.parametrize("nq,pad_to", [(16, None), (11, 16), (16, 24)])
+def test_one_shard_capped_lanes_match_the_uncapped_buffer(
+        one_shard, corpus, monkeypatch, factor, nq, pad_to):
+    """At one shard every lane lands in the shard's buffer at its own
+    position < Q*P, so the capped buffer keeps every live lane in its slot:
+    ids, distances, drops and live hops equal the uncapped buffer's, and
+    only the dead tail of the hop table goes."""
+    _, q, _ = corpus
+    q = q[:nq]
+    nprobe, bucket = 4, pad_to or nq
+    scfg = engine.SearchConfig(nprobe=nprobe, ef=24, k=10,
+                               lane_capacity_factor=factor)
+    res, stats = engine.PIMCQGEngine(*one_shard, scfg).search(
+        q, pad_to=pad_to)
+    with monkeypatch.context() as m:
+        m.setattr(engine, "_lane_capacity", _uncapped_capacity)
+        ref, ref_stats = engine.PIMCQGEngine(*one_shard, scfg).search(
+            q, pad_to=pad_to)
+    np.testing.assert_array_equal(np.asarray(res.ids), np.asarray(ref.ids))
+    np.testing.assert_array_equal(np.asarray(res.dists),
+                                  np.asarray(ref.dists))
+    assert int(stats.dropped_lanes) == int(ref_stats.dropped_lanes) == 0
+    hops, ref_hops = np.asarray(stats.hops), np.asarray(ref_stats.hops)
+    lanes = bucket * nprobe
+    assert hops.shape == (1, lanes)
+    assert ref_hops.shape == (1, _uncapped_capacity(bucket, nprobe, 1,
+                                                    factor))
+    np.testing.assert_array_equal(hops, ref_hops[:, :lanes])
+    assert not ref_hops[:, lanes:].any()
+    live = nq * nprobe
+    assert (hops[:, :live] > 0).all()
+    assert not hops[:, live:].any()
+
+
 def test_rerank_sort_dedup_matches_pairwise_reference():
     """Regression for the (Q, C, C) pairwise dedup mask: the sort-based
     dedup must keep exactly the FIRST occurrence of every candidate id

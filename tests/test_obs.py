@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
-from repro.core import compact_index, engine, obs
+from repro.core import compact_index, engine, obs, placement
 from repro.core.execbackend import InProcBackend
 from repro.core.topology import TopologyConfig
 from repro.data.synthetic import clustered_vectors, query_set
@@ -217,6 +217,22 @@ def test_counters_match_the_lane_arithmetic(eng_q):
     c = rep.counters
     assert c["lane_slots"] == 2 * 2 * 32
     assert c["live_lanes"] == 2 * 16 * 2 - c["dropped_lanes"]
+
+
+def test_one_shard_whole_flushes_fill_every_lane_slot(eng_q):
+    """One inner shard: the lane buffer is capped at the Q * P lanes a
+    flush has, so whole 16-query flushes at nprobe 2 leave no dead slot."""
+    eng, q = eng_q
+    sizes = np.asarray(eng.index.n_valid).astype(np.float64)
+    one = engine.PIMCQGEngine(eng.index, eng.host,
+                              placement.greedy_place(sizes, sizes, 1),
+                              eng.icfg, eng.scfg)
+    topo = TopologyConfig(**STREAM).build(one)
+    rep = topo.run(np.concatenate([q, q])[:32])
+    assert rep.flush_sizes == [16, 16]
+    c = rep.counters
+    assert c["dropped_lanes"] == 0
+    assert c["lane_slots"] == c["live_lanes"] == 2 * 16 * 2
 
 
 def test_mesh_counters_on_four_virtual_devices():
