@@ -1,7 +1,8 @@
 """The comparison that decides ``correct``.
 
 Every answer due in the window is judged against the brute-force reference
-(``corpus.exact_knn`` / ``corpus.exact_dists``) once the window has closed:
+(``corpus.exact_knn`` / ``corpus.exact_dists``, in the configuration's
+dtype and metric) once the window has closed:
 
 - ``lost``: queries that were neither answered nor shed (an answer that
   never came). Limit 0. A shed query is a refusal, counted in ``failed``.
@@ -9,17 +10,23 @@ Every answer due in the window is judged against the brute-force reference
   a distance that is not finite, or distances out of ascending order.
   Limit 0.
 - ``dist_gap``: the widest gap between a returned distance and the exact
-  squared L2 distance of the id it was returned with, over
-  ``|q|^2 + |x|^2`` (the scale of the float32 terms the distance is
-  computed from). Limit from the configuration, set from readings of the
-  program and of the control (``PERF.md``).
+  distance of the id it was returned with, over the scale of the float32
+  terms that distance is computed from: for ``l2`` the squared distance
+  over ``|q|^2 + |x|^2``, for ``ip`` the negated inner product over
+  ``|q| |x|``. On a uint8 or int8 corpus the exact distance is an
+  integer, computed without rounding. Limit from the configuration, set
+  from readings of the program and of the control (``PERF.md``).
 - ``recall``: recall@k of the answered rows against the exact top-k.
   Limit: the configuration's stated operating point.
 
 The control is the reference put in the program's place one precision
-step below the configuration's float32: its matmul in bfloat16
-(``bf16_dot``), written out so that it computes the same on every
-backend.
+step below the configuration's. For a float32 corpus (either metric):
+its matmul in bfloat16 (``bf16_dot``), written out so that it computes the
+same on every backend. bfloat16 holds every uint8 and int8 value exactly,
+so on an integer corpus that matmul is exact and no control; there the
+control is the exact answer with its distances rounded to bfloat16
+(``bf16_round``), what a program whose rerank returned bfloat16 distances
+would give.
 """
 
 from __future__ import annotations
@@ -27,7 +34,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["row_faults", "judge", "bf16_dot"]
+__all__ = ["row_faults", "judge", "bf16_dot", "bf16_round"]
 
 
 def bf16_dot(q, x):
@@ -36,6 +43,12 @@ def bf16_dot(q, x):
     computes."""
     return jnp.dot(q.astype(jnp.bfloat16), x.astype(jnp.bfloat16).T,
                    preferred_element_type=jnp.float32)
+
+
+def bf16_round(d: np.ndarray) -> np.ndarray:
+    """float32 distances rounded to the nearest bfloat16 (ties to even)
+    and back: the same on every backend."""
+    return np.asarray(d, np.float32).astype(jnp.bfloat16).astype(np.float32)
 
 
 def row_faults(ids: np.ndarray, dists: np.ndarray, n_corpus: int

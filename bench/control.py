@@ -3,13 +3,16 @@
 
     python3 bench/control.py --config sift1m --seeds 1,2,3 --queries 4096
 
-Puts the reference in the program's place one precision step down (its
-matmul in bfloat16, ``check.bf16_dot``), answers the queries a run would
-have due (pool queries in the seed's order), and judges those answers
-exactly as ``run.py`` judges the program's. It prints each number compared
-with its limit; ``dist_gap`` has to come out over its limit on every seed.
-Not run by the benchmark's own runs: it is how the limits were checked on
-the chip, and its test (``test_bench_control.py``) runs it at a small size.
+Puts the reference in the program's place one precision step down (on a
+float32 corpus its matmul in bfloat16, ``check.bf16_dot``; on a uint8 or
+int8 corpus, where that matmul is exact, its distances rounded to
+bfloat16, ``check.bf16_round``), answers the queries a run would have due
+(pool queries in the seed's order), and judges those answers exactly as
+``run.py`` judges the program's, in the configuration's dtype and metric.
+It prints each number compared with its limit; ``dist_gap`` has to come
+out over its limit on every seed. Not run by the benchmark's own runs: it
+is how the limits were checked on the chip, and its test
+(``test_bench_control.py``) runs it at a small size.
 """
 
 from __future__ import annotations
@@ -28,12 +31,13 @@ import numpy as np  # noqa: E402
 
 
 def control_verdict(cfg: dict, seed: int, n_queries: int) -> dict:
-    """Judge the bfloat16 reference's answers to ``n_queries`` due
-    queries of seed ``seed`` against the float32 reference."""
+    """Judge the control's answers to ``n_queries`` due queries of seed
+    ``seed`` against the exact reference."""
     from bench import arrivals, check, corpus
 
     key = corpus.seed_key(seed)
-    mix = corpus.Mixture.from_config(cfg["generator"])
+    mix = corpus.Mixture.from_config(cfg)
+    metric = cfg["metric"]
     x = corpus.make_corpus(key, n=cfg["n"], dim=cfg["dim"], mix=mix)
     pool = np.asarray(corpus.make_queries(key, n=cfg["n_queries"],
                                           dim=cfg["dim"], mix=mix))
@@ -41,10 +45,14 @@ def control_verdict(cfg: dict, seed: int, n_queries: int) -> dict:
                                len(pool), seed, 1.0, rate_hint=n_queries)
     uniq, inv = np.unique(order, return_inverse=True)
     k = cfg["search"]["k"]
-    ref, _ = corpus.exact_knn(pool[uniq], x, k)
-    ids, dists = corpus.exact_knn(pool[uniq], x, k, dot=check.bf16_dot)
+    ref, ref_d = corpus.exact_knn(pool[uniq], x, k, metric=metric)
+    if mix.quantize is None:
+        ids, dists = corpus.exact_knn(pool[uniq], x, k, metric=metric,
+                                      dot=check.bf16_dot)
+    else:
+        ids, dists = ref, check.bf16_round(ref_d)
     ids, dists, ref = ids[inv], dists[inv], ref[inv]
-    exact_d, scale = corpus.exact_dists(pool[order], ids, x)
+    exact_d, scale = corpus.exact_dists(pool[order], ids, x, metric=metric)
     n = len(order)
     return check.judge(ids=ids, dists=dists.astype(np.float32),
                        answered=np.ones(n, bool), shed=np.zeros(n, bool),
@@ -58,7 +66,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--queries", type=int, default=4096)
     args = ap.parse_args(argv)
-    cfg = json.loads((BENCH / "configs" / f"{args.config}.json").read_text())
+    from bench import run
+    cfg = run.load_config(args.config)
     for seed in (int(s) for s in args.seeds.split(",")):
         v = control_verdict(cfg, seed, args.queries)
         print(json.dumps({"config": args.config, "seed": seed,

@@ -9,7 +9,8 @@ configuration (``bench/configs/<config>.json``), traffic mix
 (``bench/metrics/<metric>.py``) are found by name. The run:
 
 1. set-up: draws the corpus and the query pool from the seed on the
-   device, builds the index (``PIMCQGEngine.build``), builds the topology
+   device, in the configuration's ``dtype``, builds the index
+   (``PIMCQGEngine.build``), builds the topology
    (``TopologyConfig(...).build``), compiles it (``ServingTopology.warm``)
    and serves a warm-up stream; ``setup_s`` runs from process start to the
    window's start;
@@ -20,7 +21,8 @@ configuration (``bench/configs/<config>.json``), traffic mix
    queries are read from ``PIMCQGEngine.search``;
 3. check: the program's state is freed, the corpus is drawn again and
    every answer due in the window is compared with the brute-force
-   reference (``check.py``).
+   reference in the configuration's ``metric`` (``check.py``). The
+   program is not told the metric: one that ranks by another fails.
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with
@@ -76,6 +78,15 @@ class Context:
 
 def load_json(path: Path) -> dict:
     return json.loads(Path(path).read_text())
+
+
+def load_config(name: str) -> dict:
+    """``bench/configs/<name>.json``, refused where its ``dtype``,
+    ``metric`` or ``generator`` is not one the harness takes."""
+    from bench import corpus
+    cfg = load_json(BENCH / "configs" / f"{name}.json")
+    corpus.Mixture.from_config(cfg)
+    return cfg
 
 
 def peaks_for(kind: str) -> dict:
@@ -155,14 +166,15 @@ def memory_peak_bytes(devices) -> int | None:
 
 
 def setup_index(cfg: dict, seed: int, log):
-    """Corpus and query pool from the seed, then the program's index.
-    Returns (engine, pool on the host, corpus rows N)."""
+    """Corpus and query pool from the seed, in the configuration's dtype,
+    then the program's index. Returns (engine, pool on the host, corpus
+    rows N)."""
     import jax
     from repro.core import compact_index, engine
     from bench import corpus
 
     key = corpus.seed_key(seed)
-    mix = corpus.Mixture.from_config(cfg["generator"])
+    mix = corpus.Mixture.from_config(cfg)
     t = time.perf_counter()
     x = corpus.make_corpus(key, n=cfg["n"], dim=cfg["dim"], mix=mix)
     pool = np.asarray(corpus.make_queries(key, n=cfg["n_queries"],
@@ -171,9 +183,9 @@ def setup_index(cfg: dict, seed: int, log):
     t_made = time.perf_counter() - t
     x_host = np.asarray(x)
     del x                                   # the program keeps its own copy
-    log(f"data: {cfg['n']:,} x {cfg['dim']} corpus and {len(pool):,} "
-        f"queries made on the device in {t_made:.3f} s, copied to the host "
-        f"in {time.perf_counter() - t - t_made:.3f} s")
+    log(f"data: {cfg['n']:,} x {cfg['dim']} {mix.dtype} corpus and "
+        f"{len(pool):,} queries made on the device in {t_made:.3f} s, "
+        f"copied to the host in {time.perf_counter() - t - t_made:.3f} s")
     t = time.perf_counter()
     eng = engine.PIMCQGEngine.build(
         jax.random.fold_in(key, 2), x_host,
@@ -209,18 +221,21 @@ def topology_config(cfg: dict, mix: dict):
 def reference(cfg: dict, seed: int, pool: np.ndarray, order: np.ndarray,
               ids: np.ndarray, rows: np.ndarray, log):
     """The exact top-k of every due query, and the exact distance and
-    scale of every id returned in ``rows``; from a fresh draw of the
-    corpus (nothing the program made is read)."""
+    scale of every id returned in ``rows``, in the configuration's metric;
+    from a fresh draw of the corpus (nothing the program made is read)."""
     from bench import corpus
     t = time.perf_counter()
+    metric = cfg["metric"]
     x = corpus.make_corpus(corpus.seed_key(seed), n=cfg["n"], dim=cfg["dim"],
-                           mix=corpus.Mixture.from_config(cfg["generator"]))
+                           mix=corpus.Mixture.from_config(cfg))
     uniq, inv = np.unique(order, return_inverse=True)
-    ref_u, _ = corpus.exact_knn(pool[uniq], x, cfg["search"]["k"])
+    ref_u, _ = corpus.exact_knn(pool[uniq], x, cfg["search"]["k"],
+                                metric=metric)
     exact_d = np.zeros(ids.shape)
     scale = np.ones(ids.shape)
     if len(rows):
-        d, s = corpus.exact_dists(pool[order[rows]], ids[rows], x)
+        d, s = corpus.exact_dists(pool[order[rows]], ids[rows], x,
+                                  metric=metric)
         exact_d[rows], scale[rows] = d, s
     del x
     log(f"reference: exact top-{cfg['search']['k']} of {len(uniq):,} "
@@ -373,7 +388,7 @@ def main(argv=None) -> int:
 
     log(f"device: {devs[0].device_kind} x {cell['chips']}; compile cache "
         f"{cache}")
-    cfg = load_json(BENCH / "configs" / f"{cell['config']}.json")
+    cfg = load_config(cell["config"])
     from bench import arrivals
     mix = arrivals.load_mix(BENCH / "traffic" / f"{cell['traffic']}.json")
     out = run_cell(bench, cell, cfg, mix, args.seed, args.seconds,

@@ -142,7 +142,7 @@ def main(argv=None) -> int:
         return 1
     from bench import corpus, run
     run.enable_compile_cache()
-    cfg = run.load_json(BENCH / "configs" / f"{args.config}.json")
+    cfg = run.load_config(args.config)
     for kv in filter(None, args.generator.split(",")):
         k, v = kv.split("=")
         cfg["generator"][k] = type(cfg["generator"][k])(v)
@@ -151,10 +151,9 @@ def main(argv=None) -> int:
     eng, pool, _ = run.setup_index(cfg, args.seed, log)
     t = time.perf_counter()
     x = corpus.make_corpus(corpus.seed_key(args.seed), n=cfg["n"],
-                           dim=cfg["dim"],
-                           mix=corpus.Mixture.from_config(cfg["generator"]))
+                           dim=cfg["dim"], mix=corpus.Mixture.from_config(cfg))
     nb = min(args.batch_queries, len(pool))
-    truth, _ = corpus.exact_knn(pool[:nb], x, cfg["k"])
+    truth, _ = corpus.exact_knn(pool[:nb], x, cfg["k"], metric=cfg["metric"])
     del x
     log(f"reference of {nb} queries in {time.perf_counter() - t:.3f} s")
     out = {"config": args.config, "seed": args.seed,

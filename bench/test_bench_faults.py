@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from bench import arrivals, run
-from bench.conftest import BENCH
+from bench.conftest import BENCH, tiny_config
 from repro.core import engine, pipeline, rerank
 
 
@@ -99,3 +99,50 @@ def test_shed_queries_fail_but_are_not_incorrect(tiny_cfg, bench_json,
     assert out["failed"] > 0
     assert out["correct"], out["checks"]
     assert np.isinf(out["metrics"]["p99_ms"]["value"])
+
+
+def test_integer_run_is_correct(bench_json):
+    """A uint8 L2 configuration served by the program as it is (which
+    casts corpus and queries to float32): exact distances, correct."""
+    out = run_tiny(tiny_config("uint8"), bench_json)
+    assert out["correct"], out["checks"]
+    assert out["checks"]["dist_gap"]["value"] == 0.0
+
+
+@pytest.mark.parametrize("fault", ["wrong_id", "distance_plus_one"])
+def test_integer_run_with_a_fault_is_not_correct(bench_json, monkeypatch,
+                                                 fault):
+    """On a uint8 corpus an id altered where it is produced, or a distance
+    off by one integer unit, makes the run not correct."""
+    search = engine.PIMCQGEngine.search
+
+    def altered(self, queries, **kw):
+        out, stats = search(self, queries, **kw)
+        ids, dists = out.ids, out.dists
+        if fault == "wrong_id":
+            n = self.host.vectors.shape[0]
+            ids = ids.at[:, -1].set((ids[:, -1] + 1) % n)
+        else:
+            dists = dists.at[:, -1].add(1.0)
+        return rerank.RerankResult(ids, dists), stats
+
+    monkeypatch.setattr(engine.PIMCQGEngine, "search", altered)
+    out = run_tiny(tiny_config("uint8"), bench_json)
+    assert not out["correct"]
+    c = out["checks"]
+    assert c["dist_gap"]["value"] > c["dist_gap"]["limit"]
+
+
+@pytest.mark.parametrize("query_shift", [0.0, 0.5])
+def test_inner_product_run_of_the_l2_program_is_not_correct(bench_json,
+                                                            query_shift):
+    """The harness does not tell the program the metric: an L2-only
+    program serving an inner-product configuration returns L2 distances
+    and L2 neighbours, and the check sees both."""
+    cfg = tiny_config("float32", "ip")
+    cfg["generator"]["query_shift"] = query_shift
+    out = run_tiny(cfg, bench_json)
+    assert not out["correct"]
+    c = out["checks"]
+    assert c["dist_gap"]["value"] > c["dist_gap"]["limit"]
+    assert c["recall"]["value"] < c["recall"]["limit"]

@@ -10,7 +10,7 @@ import sys
 
 import pytest
 
-from bench import arrivals, run
+from bench import arrivals, corpus, run
 from bench.conftest import BENCH
 
 ROOT = BENCH.parent
@@ -55,6 +55,18 @@ def test_configs_files_and_names(bench_json):
         assert cfg["search"]["k"] == cfg["k"]
         assert set(cfg["limits"]) == {"recall", "dist_gap"}
         assert cfg["n"] % cfg["generator"]["components"] == 0
+
+
+@pytest.mark.parametrize("path", sorted((BENCH / "configs").glob("*.json")),
+                         ids=lambda p: p.stem)
+def test_config_files_state_dtype_and_metric(path):
+    """Every configuration states a dtype and a metric the harness takes,
+    and quantizes its generator exactly when the dtype is an integer."""
+    cfg = json.loads(path.read_text())
+    assert cfg["dtype"] in corpus.RANGES and cfg["metric"] in corpus.METRICS
+    integer = corpus.RANGES[cfg["dtype"]] is not None
+    assert ("quantize" in cfg["generator"]) == integer
+    assert run.load_config(path.stem)["dtype"] == cfg["dtype"]
 
 
 def test_workloads(bench_json):

@@ -183,7 +183,7 @@ def tiny_topo():
     from repro.core import compact_index, engine
     from bench import corpus
     key = corpus.seed_key(7)
-    mix = corpus.Mixture.from_config(TINY["generator"])
+    mix = corpus.Mixture.from_config(TINY)
     x = np.asarray(corpus.make_corpus(key, n=TINY["n"], dim=TINY["dim"],
                                       mix=mix))
     pool = np.asarray(corpus.make_queries(key, n=256, dim=TINY["dim"],
